@@ -50,6 +50,8 @@ def main() -> None:
     args = ap.parse_args()
     quick = not args.full
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from benchmarks import (breakpoint_surface, extreme_bench,
                             fault_sweep_bench, fig3_bitflip, fig4_dim_quant,
                             fig5_alphabet, fig6_hybrid, fit_bench,

@@ -49,7 +49,7 @@ def kernels_qualify(metric: str = "l2") -> bool:
     >>> kernels_qualify("cos")        # only the l2 kernels exist
     False
     """
-    return (not kcommon.INTERPRET) and metric == "l2"
+    return (not kcommon.interpret()) and metric == "l2"
 
 
 def _predict_kernel(model: HDModel, h: jax.Array) -> jax.Array:
@@ -109,7 +109,7 @@ def loghd_head_scores(x: jax.Array, bundles: jax.Array, profiles: jax.Array,
     sites only — the caller gates on its mesh context) and to the jnp
     expansion otherwise."""
     if use_kernel is None:
-        use_kernel = not kcommon.INTERPRET
+        use_kernel = not kcommon.interpret()
     p = profiles.astype(jnp.float32)
     if use_kernel:
         lead = x.shape[:-1]

@@ -44,7 +44,6 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.api import dispatch
-from repro.compat import axis_size, shard_map_checked
 from repro.core.bundling import refine_delta, refine_epoch, symbol_targets
 from repro.hdc.conventional import (l2_normalize as _l2n, onlinehd_delta,
                                     onlinehd_step, pad_batches)
@@ -198,7 +197,7 @@ def _allreduce_delta(delta, err, axis: str, compress: Optional[str]):
     """Sum per-shard deltas over `axis`; int8 error-feedback optional."""
     if compress == "int8":
         mean, err = compressed_psum(delta, axis, err)
-        return mean * axis_size(axis), err
+        return mean * jax.lax.axis_size(axis), err
     return jax.lax.psum(delta, axis), err
 
 
@@ -231,9 +230,10 @@ def _build_onlinehd_dp(epochs: int, local_bs: int, compress: Optional[str],
         (protos, _), _ = jax.lax.scan(epoch, carry, None, length=epochs)
         return protos
 
-    return jax.jit(shard_map_checked(
+    return jax.jit(jax.shard_map(
         local_fit, mesh=mesh,
-        in_specs=(P(), P(axis), P(axis), P()), out_specs=P(), check=False))
+        in_specs=(P(), P(axis), P(axis), P()), out_specs=P(),
+        check_vma=False))
 
 
 def fused_onlinehd_fit_dp(protos: jax.Array, h: jax.Array, y: jax.Array, *,
@@ -287,10 +287,10 @@ def _build_refine_dp(epochs: int, local_bs: int, compress: Optional[str],
         (bundles, _), _ = jax.lax.scan(epoch, carry, keys)
         return bundles
 
-    return jax.jit(shard_map_checked(
+    return jax.jit(jax.shard_map(
         local_fit, mesh=mesh,
         in_specs=(P(), P(axis), P(axis), P(), P()),
-        out_specs=P(), check=False))
+        out_specs=P(), check_vma=False))
 
 
 def fused_refine_bundles_dp(bundles: jax.Array, h: jax.Array, y: jax.Array,

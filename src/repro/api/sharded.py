@@ -49,7 +49,6 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.api import dispatch
 from repro.api.fit_engine import fused_refine_bundles, fused_refine_bundles_dp
 from repro.api.models import MODEL_CLASSES, LogHDModel, _shape
-from repro.compat import shard_map_checked
 from repro.core import codebook as cb
 from repro.core.bundling import build_bundles
 from repro.core.profiles import activations, segment_profile_means
@@ -163,9 +162,9 @@ def sharded_decode(profiles: jax.Array, acts: jax.Array, *, n_shards: int,
         win = jnp.argmax(all_s, axis=0)                         # first max
         return jnp.take_along_axis(all_i, win[None, :], axis=0)[0]
 
-    fn = shard_map_checked(local, mesh=mesh,
-                           in_specs=(CLASS_SHARDED, P()), out_specs=P(),
-                           check=False)
+    fn = jax.shard_map(local, mesh=mesh,
+                       in_specs=(CLASS_SHARDED, P()), out_specs=P(),
+                       check_vma=False)
     return fn(profiles, acts)
 
 
@@ -246,9 +245,9 @@ def sharded_estimate_profiles(bundles: jax.Array, h: jax.Array,
             start = (jax.lax.axis_index("class") * c_loc).astype(ids.dtype)
             return segment_profile_means(a, ids - start, c_loc)
 
-        return jax.jit(shard_map_checked(
+        return jax.jit(jax.shard_map(
             local, mesh=mesh, in_specs=(P(), P()),
-            out_specs=CLASS_SHARDED, check=False))
+            out_specs=CLASS_SHARDED, check_vma=False))
 
     fn = _cached(("profiles", n_shards, c_loc), build)
     return fn(acts, y)

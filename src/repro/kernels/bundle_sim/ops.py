@@ -26,7 +26,7 @@ def bundle_similarity(h: jax.Array, m: jax.Array, *, block_b: int = 256,
     h: (B, D) float (any of f32/bf16); m: (n, D).  Returns (B, n) f32.
     """
     if interpret is None:
-        interpret = common.INTERPRET
+        interpret = common.interpret()
     b, d = h.shape
     n = m.shape[0]
     block_b = min(block_b, common.round_up(b, common.sublane(h.dtype)))

@@ -30,7 +30,7 @@ def bundle_update(m: jax.Array, c: jax.Array, h: jax.Array, lr, *,
     sweeping it never retraces).  Returns (n, D) f32.
     """
     if interpret is None:
-        interpret = common.INTERPRET
+        interpret = common.interpret()
     n, d = m.shape
     b = h.shape[0]
     block_d = min(block_d, common.round_up(d, 128))

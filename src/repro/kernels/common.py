@@ -8,7 +8,8 @@ All kernels follow the same conventions:
   * inputs are zero-padded by the ops.py wrappers to tile multiples (zeros
     are exact identities for dot products and sums of squares), and outputs
     sliced back — so the kernels themselves never see ragged blocks,
-  * `interpret=True` on CPU (this container) and compiled mode on real TPUs.
+  * interpret mode off the TPU and compiled mode on it, decided by
+    ``interpret()`` when a kernel is traced.
 """
 
 from __future__ import annotations
@@ -18,8 +19,12 @@ import functools
 import jax
 import jax.numpy as jnp
 
-# Flip to False on a real TPU runtime; tests force True on CPU.
-INTERPRET = jax.default_backend() != "tpu"
+
+def interpret() -> bool:
+    """True unless JAX's default backend is a TPU: the kernels then run in
+    Pallas interpret mode.  Asked when a kernel is traced, never while a
+    module is imported, so importing ``repro`` initializes no backend."""
+    return jax.default_backend() != "tpu"
 
 
 def round_up(x: int, m: int) -> int:

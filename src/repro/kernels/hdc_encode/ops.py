@@ -34,7 +34,7 @@ def hdc_encode(x: jax.Array, proj: jax.Array, bias: jax.Array,
     """Fused encoder: x (B, F), proj (F, D), bias (D,), center (D,) ->
     (B, D) f32, normalized exactly like repro.hdc.encoders.encode."""
     if interpret is None:
-        interpret = common.INTERPRET
+        interpret = common.interpret()
     b, f = x.shape
     d = proj.shape[1]
     block_b = min(block_b, common.round_up(b, 8))

@@ -24,7 +24,7 @@ def loghd_head_logits(h: jax.Array, m: jax.Array, p: jax.Array, *,
     n contributes zeros to dots and norms; padded V rows are sliced away;
     padded B rows are sliced away."""
     if interpret is None:
-        interpret = common.INTERPRET
+        interpret = common.interpret()
     b, d = h.shape
     n = m.shape[0]
     v = p.shape[0]

@@ -21,7 +21,7 @@ def profile_decode_scores(acts: jax.Array, profiles: jax.Array, *,
                           interpret: bool | None = None) -> jax.Array:
     """-||A - P_c||^2 decode scores.  acts (B, n), profiles (C, n) -> (B, C)."""
     if interpret is None:
-        interpret = common.INTERPRET
+        interpret = common.interpret()
     b, n = acts.shape
     c = profiles.shape[0]
     block_b = min(block_b, common.round_up(b, common.sublane(acts.dtype)))

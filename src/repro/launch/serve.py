@@ -24,9 +24,11 @@ def main():
 
     import jax
     from repro.configs import get_config, get_smoke_config
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.models.model import init_params
     from repro.runtime.serve_loop import Request, ServeLoopConfig, run_serving
 
+    enable_compile_cache()
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     params = init_params(jax.random.PRNGKey(args.seed), cfg)
     rng = np.random.default_rng(args.seed)

@@ -39,10 +39,12 @@ def main():
                         format="%(asctime)s %(name)s %(message)s")
 
     from repro.configs import get_config, get_smoke_config
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.launch.mesh import make_debug_mesh, make_production_mesh
     from repro.runtime.train_loop import (StragglerAbort, TrainLoopConfig,
                                           run_training)
 
+    enable_compile_cache()
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     mesh = None
     if args.mesh == "debug":

@@ -30,7 +30,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.compat import axis_size, shard_map_checked
 
 
 @dataclasses.dataclass(frozen=True)
@@ -69,7 +68,7 @@ def _local_moe(params: dict, cfg: MoEConfig, x: jax.Array, *,
     Returns (y (T_loc, D), aux_loss scalar)."""
     t_loc, d = x.shape
     e = cfg.n_experts
-    n_ep = axis_size(ep_axis) if ep_axis else 1
+    n_ep = jax.lax.axis_size(ep_axis) if ep_axis else 1
     e_loc = e // n_ep
 
     # ---- expert weights: manual FSDP all-gather along `fsdp_axis`
@@ -209,7 +208,7 @@ def moe_block(params: dict, cfg: MoEConfig, x: jax.Array,
             prod *= mesh.shape[a]
     x_spec = P(tuple(bdp) or None, "model" if seq_shardable else None, None)
     in_specs = ({k: param_specs[k] for k in params}, x_spec)
-    y, aux = shard_map_checked(
+    y, aux = jax.shard_map(
         body, mesh=mesh, in_specs=in_specs,
-        out_specs=(x_spec, P()), check=False)(params, x)
+        out_specs=(x_spec, P()), check_vma=False)(params, x)
     return y, aux

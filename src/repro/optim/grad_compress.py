@@ -43,8 +43,7 @@ def compressed_psum(grad: jax.Array, axis: str, error: jax.Array,
     # per-block values instead of codes when scales differ.  We psum
     # (codes * scale) reconstructions, which is equivalent to psumming deq.
     summed = jax.lax.psum(deq, axis)
-    from repro.compat import axis_size
-    n = axis_size(axis)
+    n = jax.lax.axis_size(axis)
     return summed / n, new_error
 
 

@@ -263,3 +263,22 @@ def test_serving_loop_accepts_empty_prompt():
     assert set(out) == {0, 1}
     assert 1 <= len(out[0]) <= 4
     assert all(0 <= t < 64 for t in out[0])
+
+
+def test_import_initializes_no_backend():
+    """Importing the library asks JAX for no backend: whether kernels run
+    compiled is decided when they are traced, so an import never takes a
+    chip, and a later ``jax.devices()`` still sees the real backend."""
+    import os
+    import subprocess
+    import sys
+    code = ("import repro.api, repro.serving, repro.faults\n"
+            "import repro.launch.compile_cache\n"
+            "from jax._src import xla_bridge\n"
+            "assert not xla_bridge.backends_are_initialized()\n")
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ, PYTHONPATH=os.path.join(root, "src"),
+               JAX_PLATFORMS="cpu")
+    proc = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                          text=True, env=env, timeout=300)
+    assert proc.returncode == 0, proc.stderr[-2000:]

@@ -94,16 +94,16 @@ ELASTIC_SCRIPT = textwrap.dedent("""
     import jax, jax.numpy as jnp, numpy as np
     from jax.sharding import NamedSharding, PartitionSpec as P
     from repro.checkpoint.ckpt import save_checkpoint, restore_checkpoint
+    from repro.launch.mesh import make_mesh
 
     tree = {{"w": jnp.arange(64, dtype=jnp.float32).reshape(8, 8)}}
-    mesh1 = jax.make_mesh((2, 2), ("data", "model"),
-                          devices=jax.devices()[:4])
+    mesh1 = make_mesh((2, 2), ("data", "model"), jax.devices()[:4])
     sh1 = {{"w": NamedSharding(mesh1, P("data", "model"))}}
     placed = jax.device_put(tree, sh1)
     save_checkpoint("{ckpt}", 1, placed)
 
     # restore onto a DIFFERENT mesh shape and device count
-    mesh2 = jax.make_mesh((8, 1), ("data", "model"))
+    mesh2 = make_mesh((8, 1), ("data", "model"))
     sh2 = {{"w": NamedSharding(mesh2, P("data", "model"))}}
     out = restore_checkpoint("{ckpt}", 1, tree, sh2)
     np.testing.assert_array_equal(np.asarray(out["w"]), np.asarray(tree["w"]))

@@ -20,7 +20,8 @@ def _run(body: str, timeout=600):
     script = ("import os\n"
               "os.environ['XLA_FLAGS'] = "
               "'--xla_force_host_platform_device_count=8'\n"
-              f"import sys; sys.path.insert(0, {SRC!r})\n" + body)
+              f"import sys; sys.path.insert(0, {SRC!r})\n"
+              "from repro.launch.mesh import make_mesh\n" + body)
     out = subprocess.run([sys.executable, "-c", script],
                          capture_output=True, text=True, timeout=timeout)
     assert out.returncode == 0 and "OK" in out.stdout, \
@@ -31,7 +32,7 @@ def test_moe_shard_map_matches_reference():
     _run(textwrap.dedent("""
         import jax, jax.numpy as jnp, numpy as np
         from repro.models.moe import MoEConfig, init_moe, moe_block
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         cfg = MoEConfig(d_model=32, d_ff=16, n_experts=8, top_k=2,
                         capacity_factor=8.0)  # high cf: no drops -> exact
         params = init_moe(jax.random.PRNGKey(0), cfg, jnp.float32)
@@ -57,7 +58,7 @@ def test_sharded_train_step_runs_and_matches():
         from jax.sharding import NamedSharding
         cfg = dataclasses.replace(get_smoke_config("qwen3-1.7b"),
                                   vocab=128, n_periods=1)
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         params = init_params(jax.random.PRNGKey(0), cfg)
         tok = jax.random.randint(jax.random.PRNGKey(1), (4, 32), 0, 128)
         tgt = jnp.roll(tok, -1, 1)
@@ -82,7 +83,7 @@ def test_seq_sharded_flash_decode_matches():
                                             decode_attention,
                                             decode_attention_seqsharded,
                                             init_kv_cache)
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = make_mesh((8,), ("data",))
         cfg = AttnConfig(d_model=32, n_heads=4, n_kv_heads=2, head_dim=8)
         params = init_attn(jax.random.PRNGKey(0), cfg, jnp.float32)
         S = 64
@@ -99,12 +100,11 @@ def test_seq_sharded_flash_decode_matches():
             out, newc = decode_attention_seqsharded(p, cfg, x, c, pos,
                                                     axis="data")
             return out, newc
-        from repro.compat import shard_map_checked
-        got, _ = jax.jit(shard_map_checked(
+        got, _ = jax.jit(jax.shard_map(
             body, mesh=mesh,
             in_specs=(P(), P(), {"k": P(None, "data"), "v": P(None, "data")}),
             out_specs=(P(), {"k": P(None, "data"), "v": P(None, "data")}),
-            check=False))(params, x, cache)
+            check_vma=False))(params, x, cache)
         np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                    rtol=2e-4, atol=2e-4)
         print("OK")
@@ -116,17 +116,16 @@ def test_grad_compression_error_feedback():
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P
         from repro.optim.grad_compress import compressed_psum
-        mesh = jax.make_mesh((8,), ("pod",))
+        mesh = make_mesh((8,), ("pod",))
         g_global = jax.random.normal(jax.random.PRNGKey(0), (8, 64, 32))
 
         def body(g, err):
             mean, new_err = compressed_psum(g[0], "pod", err[0])
             return mean[None], new_err[None]
         err0 = jnp.zeros((8, 64, 32))
-        from repro.compat import shard_map_checked
-        mean, err = jax.jit(shard_map_checked(
+        mean, err = jax.jit(jax.shard_map(
             body, mesh=mesh, in_specs=(P("pod"), P("pod")),
-            out_specs=(P("pod"), P("pod")), check=False))(g_global, err0)
+            out_specs=(P("pod"), P("pod")), check_vma=False))(g_global, err0)
         want = jnp.mean(g_global, axis=0)
         # int8 quantized mean within a couple scale steps of the true mean
         scale = jnp.max(jnp.abs(g_global)) / 127.0
@@ -143,7 +142,7 @@ def test_multipod_mesh_builds():
         import jax
         # 8 host devices: shrink the production mesh factors but keep the
         # 3-axis (pod, data, model) structure
-        mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+        mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
         assert mesh.shape == {"pod": 2, "data": 2, "model": 2}
         print("OK")
     """))
@@ -157,7 +156,7 @@ def test_fused_fit_dp_matches_serial():
         import jax, jax.numpy as jnp, numpy as np
         from repro.api import fit_engine
         from repro.hdc.conventional import class_prototypes, l2_normalize
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = make_mesh((8,), ("data",))
         n, d, c, bs = 512, 128, 7, 64
         ks = jax.random.split(jax.random.PRNGKey(0), 2)
         h = l2_normalize(jax.random.normal(ks[0], (n, d)))
@@ -207,7 +206,7 @@ def test_fused_refine_dp_reduces_target_error():
         from repro.core.bundling import symbol_targets
         from repro.core.codebook import build_codebook
         from repro.hdc.conventional import l2_normalize
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = make_mesh((8,), ("data",))
         n, d, c = 512, 128, 7
         ks = jax.random.split(jax.random.PRNGKey(1), 3)
         h = l2_normalize(jax.random.normal(ks[0], (n, d)))

@@ -198,6 +198,35 @@ def test_flip_corrupt_traced_p_and_seed():
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
+def test_flip_corrupt_nested_vmap_is_draw_axis():
+    """The sweep's vmap(p) o vmap(seed) folds into the kernel's draw axis:
+    every (p, seed) member equals its own single-draw oracle, bit-exact."""
+    w = jax.random.normal(jax.random.PRNGKey(4), (26, 700))
+    q = quantize(w, 4)
+    ps = jnp.asarray([0.0, 0.1, 1.0], jnp.float32)
+    seeds = jnp.asarray([3, 11], jnp.int32)
+    f = jax.jit(jax.vmap(lambda p: jax.vmap(
+        lambda s: flip_corrupt(q.codes, q.scale, 4, p, s,
+                               interpret=True))(seeds)))
+    got = np.asarray(f(ps))
+    assert got.shape == (3, 2, 26, 700)
+    for a, p in enumerate(ps):
+        for b, s in enumerate(seeds):
+            want = flip_corrupt_ref(q.codes, q.scale, p, s, bits=4)
+            np.testing.assert_array_equal(got[a, b], np.asarray(want))
+
+    # a batch of different stored words (vmap over codes and scale) is one
+    # kernel call per member, still bit-exact
+    qs = [quantize(w * (i + 1), 4) for i in range(2)]
+    codes = jnp.stack([q.codes for q in qs])
+    scales = jnp.stack([q.scale for q in qs])
+    got = np.asarray(jax.vmap(lambda c, sc: flip_corrupt(
+        c, sc, 4, 0.1, 5, interpret=True))(codes, scales))
+    for i, q in enumerate(qs):
+        want = flip_corrupt_ref(q.codes, q.scale, 0.1, 5, bits=4)
+        np.testing.assert_array_equal(got[i], np.asarray(want))
+
+
 BU_SHAPES = [
     (5, 32, 512),      # tiny, single D tile
     (26, 100, 1000),   # ISOLET-like C, ragged B and D

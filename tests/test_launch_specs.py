@@ -32,8 +32,9 @@ def test_cell_specs_build_for_all_cells():
         import jax
         from repro.configs import ARCH_NAMES, get_config
         from repro.configs.base import SHAPES
+        from repro.launch.mesh import make_mesh
         from repro.launch.specs import cell_specs
-        mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+        mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
         built = 0
         for arch in ARCH_NAMES:
             cfg = get_config(arch)
@@ -81,3 +82,23 @@ def test_collective_parser():
     assert out["bytes"]["all-gather"] == 8 * 128 * 2
     assert out["bytes"]["all-reduce"] == 16 * 16 * 4
     assert out["total_bytes"] == 8 * 128 * 2 + 16 * 16 * 4 + 4
+
+
+def test_compile_cache_placement(monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR wins and nothing is set in code; without
+    it the cache goes to the checkout's fixed .jax_cache directory."""
+    import jax
+    from repro.launch.compile_cache import CHECKOUT, enable_compile_cache
+    was = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere")
+        jax.config.update("jax_compilation_cache_dir", None)
+        assert enable_compile_cache() == "/elsewhere"
+        assert jax.config.jax_compilation_cache_dir is None
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        path = enable_compile_cache()
+        assert path == os.path.join(str(CHECKOUT), ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == path
+        assert os.path.isdir(os.path.join(str(CHECKOUT), "src", "repro"))
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was)

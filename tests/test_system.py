@@ -94,3 +94,20 @@ def test_sparsehd_baseline_works(isolet_small):
     acc = accuracy(clf.model, fx["h_te"], fx["y_te"])
     assert acc > 0.8
     assert clf.model.protos.shape[1] == int(0.4 * 4096)
+
+
+@pytest.mark.parametrize("args", [[], ["--four-chips"]])
+def test_chip_smoke_fails_without_a_tpu(args):
+    """The chip smoke run never falls back to the CPU: with no TPU it exits
+    non-zero and prints no result line."""
+    import os
+    import subprocess
+    import sys
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    proc = subprocess.run(
+        [sys.executable, os.path.join(root, "chip_smoke.py"), *args],
+        capture_output=True, text=True, env=env, timeout=300)
+    assert proc.returncode != 0
+    assert '"ok"' not in proc.stdout
+    assert "no TPU" in proc.stderr
