@@ -27,6 +27,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.api import dispatch
 from repro.api.models import HDModel
@@ -70,7 +71,8 @@ class BucketStats:
     """Per-(family, bucket) executable accounting."""
     hits: int = 0
     misses: int = 0          # first use of a (family key, bucket) pair
-    padded_rows: int = 0     # total pad rows dispatched (wasted work proxy)
+    padded_rows: int = 0     # pad rows dispatched, added by pad_to_bucket
+                             # or by predict (wasted work proxy)
 
     @property
     def calls(self) -> int:
@@ -115,6 +117,25 @@ class BucketedPredict:
             if n <= b:
                 return b
         return self.buckets[-1]
+
+    def pad_to_bucket(self, xs: np.ndarray) -> np.ndarray:
+        """Host rows (n, ...) zero-padded to ``bucket_for(n)`` rows, the pad
+        counted in ``stats.padded_rows``.  The service pads here, before
+        encode, so the encoder also compiles once per bucket.
+
+        >>> cache = BucketedPredict(buckets=(1, 2, 4, 8))
+        >>> cache.pad_to_bucket(np.ones((3, 2), np.float32)).shape
+        (4, 2)
+        >>> cache.stats.padded_rows
+        1
+        """
+        n = xs.shape[0]
+        bucket = self.bucket_for(n)
+        if n >= bucket:
+            return xs
+        self.stats.padded_rows += bucket - n
+        return np.concatenate(
+            [xs, np.zeros((bucket - n,) + xs.shape[1:], xs.dtype)])
 
     def _family_key(self, model: HDModel,
                     use_kernels: Optional[bool]) -> tuple:

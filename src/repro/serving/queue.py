@@ -43,6 +43,7 @@ from __future__ import annotations
 import collections
 import itertools
 import threading
+import time
 from concurrent.futures import CancelledError
 from dataclasses import dataclass, field
 from typing import Iterator, Optional
@@ -198,6 +199,7 @@ class PredictRequest:
     x: np.ndarray                 # (F,) raw features or (D,) encoded
     encoded: bool = False         # x is already phi(x)
     t_arrival: float = 0.0        # load-gen timestamp (service-clock seconds)
+    t_enqueue: float = 0.0        # time.perf_counter() when pushed
     future: PredictFuture = field(default_factory=PredictFuture)
 
     @property
@@ -218,7 +220,8 @@ class RequestQueue:
     thread can share the queue.
 
     ``max_group_wait_cycles`` records the worst head-of-group wait observed
-    (in admit cycles) — the serve bench's fairness stat.
+    (in admit cycles) — the serve bench's fairness stat.  ``queue_wait_s``
+    sums, over admitted requests, the seconds from ``push`` to ``admit``.
 
     ``max_depth`` bounds the total queued requests across all groups:
     a ``push`` past the bound raises ``QueueFullError`` and increments
@@ -240,6 +243,7 @@ class RequestQueue:
         self.cycles = 0
         self.rejected = 0
         self.max_group_wait_cycles = 0
+        self.queue_wait_s = 0.0
 
     def __len__(self) -> int:
         with self._lock:
@@ -276,6 +280,7 @@ class RequestQueue:
             if not sub:                      # group becomes ready this cycle
                 self._ring.append(group)
                 self._waiting_since[group] = self.cycles
+            req.t_enqueue = time.perf_counter()
             sub.append(req)
         return req.future
 
@@ -288,6 +293,8 @@ class RequestQueue:
         cancelled while queued.  An admit on an empty queue is not a cycle.
         """
         with self._lock:
+            now = time.perf_counter()
+            waited = 0.0
             batch: list[PredictRequest] = []
             while self._ring and not batch:
                 group = self._ring.popleft()
@@ -298,6 +305,7 @@ class RequestQueue:
                     req = sub.popleft()
                     if req.future.cancelled():
                         continue
+                    waited += now - req.t_enqueue
                     batch.append(req)
                 if sub:                      # backlog: rotate to the tail
                     self._ring.append(group)
@@ -312,4 +320,5 @@ class RequestQueue:
                 return []
             self.admitted += len(batch)
             self.cycles += 1
+            self.queue_wait_s += waited
             return batch

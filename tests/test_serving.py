@@ -7,9 +7,13 @@ executables), the background dispatch thread, quantized (int8) device
 residency, bucket selection and padding correctness, jit-cache hit
 accounting across mixed batch sizes (the no-retrace-per-request contract),
 byte-identical predictions vs the direct dispatch path for every registered
-family, and the single cache-invalidation entry point."""
+family, the single cache-invalidation entry point, and the service's
+profiler spans and counters (queue wait, padded rows, stalls)."""
 
 import functools
+import pathlib
+import threading
+import time
 from concurrent.futures import CancelledError
 
 import jax
@@ -551,3 +555,129 @@ def test_open_loop_counts_rejections_under_bounded_queue():
     assert res.n_rejected == svc.stats()["rejected"]
     assert len(svc.queue) == 0
     assert "n_rejected" in res.to_record()
+
+
+# -------------------------------------------------- spans and counters --
+
+STEP_CHILDREN = ["serve.assemble", "serve.bind", "serve.encode",
+                 "serve.predict", "serve.put"]
+
+
+def _serve_lines(log_dir):
+    """serve.* events of each host line (thread) of the recorded trace."""
+    from jax.profiler import ProfileData
+    path, = pathlib.Path(log_dir).rglob("*.xplane.pb")
+    data = ProfileData.from_file(str(path))
+    lines = [[(ev.name, ev.start_ns, ev.end_ns) for ev in ln.events
+              if ev.name.startswith("serve.")]
+             for plane in data.planes if plane.name.startswith("/host:")
+             for ln in plane.lines]
+    return [ln for ln in lines if ln]
+
+
+def test_serve_forever_traces_one_span_tree_per_cycle(tmp_path):
+    """Under the profiler every non-empty cycle is one serve.step holding
+    its five stages, all on the dispatch thread's line; admission and
+    the idle wait are spans of that line too."""
+    clf = _fitted("conventional")
+    x, _ = _data()
+    svc = ClassifierService({"m": clf.model}, max_batch=4, buckets=(1, 2, 4))
+    svc.warmup()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        svc.serve_forever()
+        time.sleep(0.05)                    # idle first: serve.wait spans
+        futs = [svc.submit("m", np.asarray(x[i])) for i in range(10)]
+        got = [f.result(timeout=30.0) for f in futs]
+        svc.shutdown()
+    finally:
+        jax.profiler.stop_trace()
+    assert got == [int(v) for v in clf.predict(x[:10])]
+    line, = _serve_lines(tmp_path)
+    steps = [(s, e) for n, s, e in line if n == "serve.step"]
+    assert len(steps) == svc.stats()["cycles"] >= 3
+    for s, e in steps:
+        inside = sorted(n for n, cs, ce in line
+                        if s <= cs and ce <= e and n != "serve.step")
+        assert inside == STEP_CHILDREN
+    names = {n for n, _, _ in line}
+    assert names == {"serve.admit", "serve.step", "serve.wait",
+                     *STEP_CHILDREN}
+
+
+def test_queue_wait_grows_with_a_held_queue():
+    clf = _fitted("conventional")
+    x, _ = _data()
+    svc = ClassifierService({"m": clf.model}, max_batch=8)
+    for i in range(3):
+        svc.submit("m", np.asarray(x[i]))
+    time.sleep(0.05)
+    assert svc.stats()["queue_wait_s"] == 0.0      # nothing admitted yet
+    svc.step()
+    held = svc.stats()["queue_wait_s"]
+    assert held >= 3 * 0.05
+    svc.submit("m", np.asarray(x[3]))
+    time.sleep(0.02)
+    svc.step()
+    assert svc.stats()["queue_wait_s"] >= held + 0.02
+
+
+def test_service_pad_counts_in_padded_rows():
+    """The service pads before encode; the bucket cache counts that pad."""
+    clf = _fitted("conventional")
+    x, _ = _data()
+    svc = ClassifierService({"m": clf.model}, max_batch=8,
+                            buckets=(1, 2, 4, 8))
+    futs = [svc.submit("m", np.asarray(x[i])) for i in range(3)]
+    assert svc.run_until_drained() == 3              # one batch in bucket 4
+    assert svc.stats()["bucket_cache"]["padded_rows"] == 1
+    assert [f.result() for f in futs] == [int(v) for v in clf.predict(x[:3])]
+
+
+class _LateWake(threading.Event):
+    """An idle wait that overruns its timeout once, by ``late`` seconds."""
+
+    def __init__(self, late):
+        super().__init__()
+        self.late = late
+
+    def wait(self, timeout=None):
+        if self.late:
+            time.sleep(timeout + self.late)
+            self.late = 0
+            return False
+        return super().wait(timeout)
+
+
+@pytest.mark.parametrize("slow", ["step", "wait"])
+def test_dispatch_loop_counts_an_overrun_as_one_stall(slow, monkeypatch):
+    """A step 60 ms long, or an idle wait 60 ms past ``poll_s``, is one
+    stall; ``stall_s`` holds its whole length."""
+    clf = _fitted("conventional")
+    x, _ = _data()
+    svc = ClassifierService({"m": clf.model}, max_batch=4, buckets=(1, 2, 4))
+    svc.warmup()
+    late = 0.06
+    if slow == "step":
+        real = svc.bucket_cache.predict
+
+        def slow_predict(*args, **kw):
+            time.sleep(late)
+            return real(*args, **kw)
+
+        monkeypatch.setattr(svc.bucket_cache, "predict", slow_predict)
+    else:
+        svc._work = _LateWake(late)
+    svc.serve_forever(poll_s=1.0)    # a normal wait ends well inside poll_s
+    try:
+        if slow == "step":
+            assert svc.submit("m", np.asarray(x[0])).result(timeout=30.0) \
+                == int(clf.predict(x[:1])[0])
+        deadline = time.perf_counter() + 30.0
+        while svc.stats()["stalls"] == 0 and time.perf_counter() < deadline:
+            time.sleep(0.01)
+    finally:
+        svc.shutdown()
+    st = svc.stats()
+    assert st["stalls"] == 1
+    assert st["stall_s"] >= late + (1.0 if slow == "wait" else 0.0)
