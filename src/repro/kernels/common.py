@@ -7,7 +7,9 @@ All kernels follow the same conventions:
     for bf16; the MXU prefers 128x128 operand tiles),
   * inputs are zero-padded by the ops.py wrappers to tile multiples (zeros
     are exact identities for dot products and sums of squares), and outputs
-    sliced back — so the kernels themselves never see ragged blocks,
+    sliced back — so the kernels see no ragged blocks, except
+    profile_decode: it reads the profile table unpadded, and the lanes of
+    its last, ragged C tile past C are computed but never stored,
   * interpret mode off the TPU and compiled mode on it, decided by
     ``interpret()`` when a kernel is traced.
 """

@@ -6,20 +6,24 @@ which keeps the argmax semantics of Eq. 7 while turning the decode into one
 (bm, n) x (n, bc) MXU matmul plus rank-1 biases — the streaming form of the
 ASIC's decode stage (paper Fig. 2c).
 
-  * grid = (B tiles, C tiles); n (the activation width) is small and kept
-    whole inside each block — no reduction loop is needed,
-  * ||P_c||^2 and ||A_b||^2 are computed in-block (cheap: O(bc*n), O(bm*n)),
-    so profiles are read from HBM exactly once per B tile,
-  * used both at classifier scale (C <= a few hundred) and at LM-head scale
-    (C = vocab, e.g. 151936) where the C grid axis does the heavy tiling.
+  * the profiles are read class-minor, as the (n, C) transpose of the stored
+    (C, n) table: a block is (n, bc), n whole (no padding to 128 lanes), so
+    each step streams n rows of bc contiguous classes,
+  * grid = (B tiles, cdiv(C, bc)); the last C tile is ragged.  Its lanes
+    past C compute values that are never stored, and each score column
+    depends only on its own profile column, so nothing leaks into real ones,
+  * ||P_c||^2 (a sum over sublanes) and ||A_b||^2 are computed in-block
+    (cheap: O(bc*n), O(bm*n)), so profiles are read from HBM exactly once
+    per B tile,
+  * used at classifier scale (C <= a few hundred, one tile) and at extreme
+    scale (C = 1.3M) where the C grid axis does the heavy tiling.
 
-VMEM per step (bm=256, bc=512, n=128 padded): 256*128*4 + 512*128*4 +
-256*512*4 ~= 0.9 MB.
+VMEM per step (double-buffered, f32, n rounded up to 8 sublanes):
+2 * bc * 4 * (round_up(n, 8) + bm), plus the (bm, n) activations; at
+bm=64, n=21, bc=8192: 2 * 8192 * 4 * (24 + 64) ~= 5.8 MB.
 """
 
 from __future__ import annotations
-
-import functools
 
 import jax
 import jax.numpy as jnp
@@ -28,33 +32,34 @@ from jax.experimental import pallas as pl
 
 def _kernel(a_ref, p_ref, out_ref):
     a = a_ref[...].astype(jnp.float32)                     # (bm, n)
-    p = p_ref[...].astype(jnp.float32)                     # (bc, n)
+    p = p_ref[...].astype(jnp.float32)                     # (n, bc)
     dots = jax.lax.dot_general(
-        a, p, (((1,), (1,)), ((), ())),
+        a, p, (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)                # (bm, bc)
-    p_sq = jnp.sum(p * p, axis=-1)[None, :]                # (1, bc)
-    a_sq = jnp.sum(a * a, axis=-1)[:, None]                # (bm, 1)
+    p_sq = jnp.sum(p * p, axis=0)[None, :]                 # (1, bc)
+    a_sq = jnp.sum(a * a, axis=1)[:, None]                 # (bm, 1)
     out_ref[...] = (2.0 * dots - p_sq - a_sq).astype(out_ref.dtype)
 
 
-def profile_decode_pallas(acts: jax.Array, profiles: jax.Array, *,
-                          block_b: int = 256, block_c: int = 512,
+def profile_decode_pallas(acts: jax.Array, profiles_t: jax.Array, *,
+                          block_b: int, block_c: int,
                           interpret: bool = True) -> jax.Array:
-    """acts: (B, n), profiles: (C, n); returns (B, C) f32 scores.
-    Shapes must be pre-padded to tile multiples (ops.py handles that)."""
+    """acts: (B, n), profiles_t: (n, C); returns (B, C) f32 scores.
+    B must be a multiple of block_b (ops.py pads it); C need not be a
+    multiple of block_c."""
     b, n = acts.shape
-    c, n2 = profiles.shape
+    n2, c = profiles_t.shape
     assert n == n2
-    assert b % block_b == 0 and c % block_c == 0
+    assert b % block_b == 0
 
     return pl.pallas_call(
         _kernel,
-        grid=(b // block_b, c // block_c),
+        grid=(b // block_b, pl.cdiv(c, block_c)),
         in_specs=[
             pl.BlockSpec((block_b, n), lambda i, j: (i, 0)),
-            pl.BlockSpec((block_c, n), lambda i, j: (j, 0)),
+            pl.BlockSpec((n, block_c), lambda i, j: (0, j)),
         ],
         out_specs=pl.BlockSpec((block_b, block_c), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((b, c), jnp.float32),
         interpret=interpret,
-    )(acts, profiles)
+    )(acts, profiles_t)

@@ -59,8 +59,10 @@ PD_SHAPES = [
     (8, 4, 5),         # tiny
     (64, 6, 26),       # ISOLET-like
     (100, 10, 26),     # ragged batch
-    (256, 18, 2048),   # multiple C tiles
+    (256, 18, 2048),   # one C tile as wide as C
     (17, 20, 151936),  # vocab-scale C, ragged everything
+    (64, 21, 20037),   # several C tiles, the last one ragged
+    (3, 21, 26),       # C inside one tile, n = 21
 ]
 
 

@@ -12,6 +12,7 @@ without one.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -28,6 +29,7 @@ from repro.kernels.profile_decode.ops import profile_decode_scores
 
 D = 10_000                        # the paper's hypervector width
 N_ISOLET = 10                     # ceil(log2 26) + 5 extra bundles (k = 2)
+C_LFAT, N_LFAT = 1_305_265, 21    # LF-AmazonTitles-1.3M's classes, k = 2
 
 
 @pytest.fixture(scope="module")
@@ -115,21 +117,102 @@ def test_flip_corrupt_compiles_under_sweep_vmaps(one_chip):
     assert hlo.count("tpu_custom_call") == 1
 
 
-def test_loghd_kernel_predict_compiles(one_chip, monkeypatch):
+def _compile_loghd_predict(one_chip, monkeypatch, c, n, rows=64):
     """The LogHD predict executable that serving dispatches on the chip:
-    bundle_sim then profile_decode, both as kernels.  The kernels ask the
-    default backend (the CPU here) whether to interpret, so the test tells
-    them they are on a TPU, and drops traces made before and after."""
+    bundle_sim then profile_decode, both as kernels, for C classes and n
+    bundles.  The kernels ask the default backend (the CPU here) whether to
+    interpret, so this tells them they are on a TPU, and drops traces made
+    before and after."""
     from repro.kernels import common
     monkeypatch.setattr(common, "interpret", lambda: False)
     jax.clear_caches()
     model = LogHDModel(
         enc={"proj": _s((617, D)), "bias": _s((D,)), "center": _s((D,))},
-        bundles=_s((N_ISOLET, D)), profiles=_s((26, N_ISOLET)),
-        codebook=_s((26, N_ISOLET), jnp.int32))
+        bundles=_s((n, D)), profiles=_s((c, n)),
+        codebook=_s((c, n), jnp.int32))
     predict = dispatch.predict_fn(model, use_kernels=True)
     try:
-        hlo = _compile_for_chip(predict, one_chip, model, _s((64, D)))
+        return _compile_for_chip(predict, one_chip, model, _s((rows, D)))
     finally:
         jax.clear_caches()
+
+
+def test_loghd_kernel_predict_compiles(one_chip, monkeypatch):
+    hlo = _compile_loghd_predict(one_chip, monkeypatch, 26, N_ISOLET)
     assert hlo.count("tpu_custom_call") >= 2
+
+
+def _entry_ops(hlo):
+    """The ENTRY computation's instructions: name -> (type, opcode,
+    operand names)."""
+    entry = hlo[hlo.index("\nENTRY "):]
+    entry = entry[:entry.index("\n}")]
+    ops = {}
+    for line in entry.splitlines()[1:]:
+        m = re.match(r"\s*(?:ROOT )?%(\S+) = (.*?) ([\w-]+)\((.*?)\)(?:, |$)",
+                     line)
+        if m:
+            ops[m[1]] = (m[2], m[3], re.findall(r"%([\w.-]+)", m[4]))
+    return ops
+
+
+def _table_paths(hlo, table_type):
+    """Every chain of ops from the ENTRY parameter typed ``table_type`` to
+    the first op that is not a bitcast or a copy: [(name, opcode, type),
+    ...] each, the op it ends in last."""
+    ops = _entry_ops(hlo)
+    (param,) = [k for k, (t, op, _) in ops.items()
+                if op == "parameter" and t.startswith(table_type + "{")]
+    paths, todo = [], [(param, [])]
+    while todo:
+        name, path = todo.pop()
+        for user, (t, op, args) in ops.items():
+            if name in args:
+                step = path + [(user, op, t)]
+                if op in ("bitcast", "copy-start", "copy-done"):
+                    todo.append((user, step))
+                else:
+                    paths.append(step)
+    return paths
+
+
+def _table_shaped(hlo, c, n):
+    """pad, copy and transpose results shaped like the profile table or its
+    padding: a dimension from C to C plus one 8,192-lane tile, beside n or
+    128."""
+    found = []
+    for t, op, _ in _entry_ops(hlo).values():
+        if op not in ("pad", "copy", "transpose"):
+            continue
+        for dims in re.findall(r"\[(\d+),(\d+)\]", t):
+            for a, b in (dims, dims[::-1]):
+                if c <= int(a) < c + 8192 and int(b) in (n, 128):
+                    found.append((op, t))
+    return found
+
+
+def test_loghd_kernel_predict_reads_lfat_table_in_place(one_chip, monkeypatch):
+    """At LF-AmazonTitles-1.3M's shape the profile table reaches the
+    profile_decode kernel as a bitcast of the stored parameter: no per-call
+    relayout copy and no pad of the table to 128 lanes."""
+    hlo = _compile_loghd_predict(one_chip, monkeypatch, C_LFAT, N_LFAT)
+    assert _table_shaped(hlo, C_LFAT, N_LFAT) == []
+    (path,) = _table_paths(hlo, f"f32[{C_LFAT},{N_LFAT}]")
+    assert [op for _, op, _ in path] == ["bitcast", "custom-call"]
+    kernel, _, _ = path[-1]
+    assert kernel.startswith("profile_decode_scores")
+    assert 'custom_call_target="tpu_custom_call"' in next(
+        line for line in hlo.splitlines() if f"%{kernel} = " in line)
+
+
+def test_loghd_kernel_predict_isolet_table_not_relaid(one_chip, monkeypatch):
+    """At ISOLET's shape the (26, 10) table may be moved into fast memory
+    whole, but keeps its class-minor layout and is not padded on its way to
+    the kernel."""
+    hlo = _compile_loghd_predict(one_chip, monkeypatch, 26, N_ISOLET)
+    assert _table_shaped(hlo, 26, N_ISOLET) == []
+    table = f"f32[26,{N_ISOLET}]"
+    (path,) = _table_paths(hlo, table)
+    assert path[-1][0].startswith("profile_decode_scores")
+    for _, op, t in path[:-1]:
+        assert op == "bitcast" or table + "{0,1:" in t, (op, t)
