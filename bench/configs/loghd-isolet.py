@@ -18,6 +18,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from bench.loghd import Built, l2n, seed32
+from bench.reference import STAGES  # noqa: F401
 from bench.reference import loghd_scores as reference_scores  # noqa: F401
 
 

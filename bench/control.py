@@ -53,7 +53,7 @@ def readings_for_seed(cell, seed: int, seconds: float,
            "distinct_rows": int(np.unique(w.row[done]).size),
            "failed": {k: int(np.sum(v.status != DONE))
                       for k, v in windows.items()}}
-    for prec in (stated(cell.config), *precisions):
+    for prec in (stated(cell.config, cell.maker.STAGES), *precisions):
         got = {k: readings(ref, prec, built.params, built.pool,
                            v.row[v.status == DONE], v.label[v.status == DONE])
                for k, v in windows.items()}

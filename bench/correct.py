@@ -105,8 +105,8 @@ def check_labels(config: dict, maker, built, window, seed: int) -> dict:
     limits = {"missing": 0, **config["correct"]["limits"]}
     done = window.status == DONE
     rows, served = window.row[done], window.label[done]
-    got = readings(maker.reference_scores, stated(config), built.params,
-                   built.pool, rows, served)
+    got = readings(maker.reference_scores, stated(config, maker.STAGES),
+                   built.params, built.pool, rows, served)
     numbers = {
         "missing": int(np.sum((window.status != DONE)
                               & (window.status != REJECTED))),

@@ -6,8 +6,8 @@ against the normalized bundles (paper Eq. 5) and the nearest-profile decode
 
 Arrays are float32. Each of the three matmuls runs at the precision the
 configuration states for it (``precision``: projection, similarity,
-decode), "default" (one bfloat16 pass on the TPU's matrix unit, float32
-accumulation) or "highest" (float32).
+decode, the stages ``STAGES`` names), "default" (one bfloat16 pass on the
+TPU's matrix unit, float32 accumulation) or "highest" (float32).
 
 With ``dtype=jnp.bfloat16`` the same function computes every array and
 every intermediate in bfloat16: a witness one step below the stated
@@ -24,9 +24,10 @@ PRECISIONS = {"default": jax.lax.Precision.DEFAULT,
 STAGES = ("projection", "similarity", "decode")
 
 
-def stated(config: dict) -> tuple:
-    """The configuration's matmul precisions, in ``STAGES`` order."""
-    return tuple(config["precision"][s] for s in STAGES)
+def stated(config: dict, stages: tuple) -> tuple:
+    """The configuration's matmul precisions, in the order of ``stages``:
+    the ``STAGES`` its configuration module exports."""
+    return tuple(config["precision"][s] for s in stages)
 
 
 def _l2n(v, eps=1e-12):

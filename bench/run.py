@@ -44,9 +44,11 @@ class NoAccelerator(RuntimeError):
 
 
 class RunData:
-    """What the metric readers see of one run."""
+    """What the metric readers see of one run. The traced slice's counters
+    are the cell's mode's own: ``mode.delta`` of the counters at the
+    slice's start and end, where the mode defines one."""
 
-    def __init__(self, *, setup_s, window, config, peaks, trace):
+    def __init__(self, *, setup_s, window, config, peaks, trace, mode):
         self.setup_s = setup_s
         self.window = window
         self.config = config
@@ -54,8 +56,8 @@ class RunData:
         self.trace = trace
         c = window.counters
         self.slice_counters = None
-        if "slice_start" in c and "slice_end" in c:
-            from bench.modes.serve_open_loop import delta
+        delta = getattr(mode, "delta", None)
+        if delta is not None and "slice_start" in c and "slice_end" in c:
             self.slice_counters = delta(c["slice_start"], c["slice_end"])
 
     def rows_per_call(self):
@@ -157,7 +159,7 @@ def measure(cell, seed: int, seconds: float, trace: bool, *,
         device["busy_s"] = reduced.busy_s
         device["window_s"] = reduced.window_s
     run = RunData(setup_s=setup_s, window=window, config=cell.config,
-                  peaks=peaks, trace=reduced)
+                  peaks=peaks, trace=reduced, mode=cell.mode)
     metrics = {}
     for m in (cell.per_layer if trace else cell.end_to_end):
         value = spec.metric_module(m["name"]).read(run)

@@ -129,7 +129,8 @@ def test_bf16_reference_reads_above_float32():
     cell = tiny_cell()
     built = cell.maker.build(cell.config, 9)
     rows = np.random.default_rng(0).integers(0, len(built.pool), 512)
-    ref, prec = cell.maker.reference_scores, stated(cell.config)
+    ref = cell.maker.reference_scores
+    prec = stated(cell.config, cell.maker.STAGES)
     lab = control_labels(ref, prec, built.params, built.pool, rows,
                          jnp.bfloat16)
     low = readings(ref, prec, built.params, built.pool, rows, lab)
